@@ -7,10 +7,9 @@ from this file, not the CWD), so every benchmark starts with
 ``sys.path.insert(0, "src")`` boilerplate that silently breaks when the
 script is launched from anywhere but the repo root.
 
-Importing it also configures ``XLA_FLAGS`` for the jax benchmarks (see
-``XLA_THUNK_FLAG`` below) -- which is why ``import common`` must stay the
-*first* import of every benchmark script: the flag must be set before the
-first jax/XLA import anywhere in the process.
+Importing it also points jax's persistent compilation cache at a fixed
+directory (``repro.launch.compile_cache``), so repeated benchmark runs in
+one tree reuse compiled programs.
 """
 
 from __future__ import annotations
@@ -19,43 +18,20 @@ import datetime
 import hashlib
 import inspect
 import json
-import os
 import platform
 import subprocess
 import sys
 import time
 from pathlib import Path
 
-_SRC = str(Path(__file__).resolve().parent.parent / "src")
+_ROOT = Path(__file__).resolve().parent.parent
+_SRC = str(_ROOT / "src")
 if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
 
-#: The XLA:CPU thunk runtime dispatches each fused computation through a
-#: buffer-assignment interpreter -- fine for big tensor ops, ~8x overhead
-#: on the jitted arbitration program's long chains of tiny while-loop
-#: bodies.  The legacy emitter compiles the same HLO straight through;
-#: results stay bit-identical (``benchmarks/online_scaling.py`` asserts
-#: jit-vs-numpy ``BatchReport`` equality under this flag on every run).
-#: Knob: set ``RASA_BENCH_XLA_THUNK_RT=1`` to keep the stock thunk
-#: runtime instead (e.g. to measure its cost).
-XLA_THUNK_FLAG = "--xla_cpu_use_thunk_runtime=false"
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 
-
-def _setup_xla_flags() -> bool:
-    """Disable the XLA:CPU thunk runtime for this process (idempotent).
-
-    Returns whether the flag is active.  Must run before the first jax
-    import; importing :mod:`common` first does that for every benchmark.
-    """
-    if os.environ.get("RASA_BENCH_XLA_THUNK_RT") == "1":
-        return False
-    if XLA_THUNK_FLAG.split("=")[0] not in os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = \
-            (os.environ.get("XLA_FLAGS", "") + " " + XLA_THUNK_FLAG).strip()
-    return XLA_THUNK_FLAG in os.environ.get("XLA_FLAGS", "")
-
-
-XLA_THUNK_RT_DISABLED = _setup_xla_flags()
+use_compile_cache(_ROOT)
 
 RESULTS = Path(__file__).resolve().parent / "results"
 
@@ -156,28 +132,33 @@ def model_fingerprint(*sources) -> str:
     return h.hexdigest()[:16]
 
 
+def device_key() -> str:
+    """``<platform>/<device_kind>`` of the device jax computes on."""
+    import jax
+    d = jax.devices()[0]
+    return f"{d.platform}/{d.device_kind}"
+
+
 def cache_json(key: str, fn, force: bool = False,
                fingerprint: str | None = None):
     """Return the cached result for ``key``, or compute and cache ``fn()``.
 
-    With ``fingerprint`` given, the cache file embeds it and a cached result
-    is served only when its fingerprint matches -- anything else (legacy
-    un-fingerprinted files included) is recomputed.  ``force=True`` always
-    recomputes.
+    The cache file records the device (:func:`device_key`) and
+    ``fingerprint``; a cached result is served only when both match, so a
+    result computed on one platform is never served on another and editing
+    the model code invalidates it.  Anything else (legacy files included)
+    is recomputed.  ``force=True`` always recomputes.
     """
     RESULTS.mkdir(parents=True, exist_ok=True)
     p = RESULTS / f"{key}.json"
+    stamp = {"__device__": device_key(), "__fingerprint__": fingerprint}
     if p.exists() and not force:
         cached = json.loads(p.read_text())
-        wrapped = isinstance(cached, dict) and "__fingerprint__" in cached
-        if fingerprint is None:
-            return cached["data"] if wrapped else cached
-        if wrapped and cached["__fingerprint__"] == fingerprint:
+        if isinstance(cached, dict) and "data" in cached and all(
+                cached.get(k) == v for k, v in stamp.items()):
             return cached["data"]
     out = fn()
-    payload = out if fingerprint is None else \
-        {"__fingerprint__": fingerprint, "data": out}
-    p.write_text(json.dumps(payload, indent=2))
+    p.write_text(json.dumps({**stamp, "data": out}, indent=2))
     return out
 
 

@@ -127,7 +127,8 @@ def measure(arch: str, shape: str, variant: str, full: bool = True) -> dict:
     from repro.distributed.sharding import mesh_context
     from repro.launch.dryrun import build_step, parse_collectives
     from repro.launch.mesh import make_production_mesh
-    from repro.roofline.analysis import analyze_cell
+    from repro.roofline.analysis import (DRYRUN_DEVICE_KIND, analyze_cell,
+                                         peaks_for)
 
     transform = VARIANTS[variant]
     seq_len, batch, kind = SHAPES[shape]
@@ -184,7 +185,7 @@ def measure(arch: str, shape: str, variant: str, full: bool = True) -> dict:
            "collectives_per_device_bytes": d0["collectives_per_device_bytes"]}
     duf = {"cost_per_device": du["cost_per_device"],
            "collectives_per_device_bytes": du["collectives_per_device_bytes"]}
-    r = analyze_cell(cell, d0=d0f, du=duf)
+    r = analyze_cell(cell, peaks_for(DRYRUN_DEVICE_KIND), d0=d0f, du=duf)
     out["roofline"] = {
         "compute_s": r.compute_s, "memory_s": r.memory_s,
         "collective_s": r.collective_s, "dominant": r.dominant,

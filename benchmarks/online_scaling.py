@@ -65,16 +65,11 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import os
 import pickle
 import resource
 import time
 from pathlib import Path
 
-# importing common first also disables the XLA:CPU thunk runtime for this
-# process -- ~8x on this program's tiny while-loop bodies, bit-identical
-# results (the parity asserts below run under the flag; see
-# common.XLA_THUNK_FLAG for the single documented knob)
 import common  # noqa: F401  -- puts <repo>/src on sys.path
 
 from repro.core.fastsim import SNAP_STRIDE  # noqa: E402
@@ -177,7 +172,6 @@ def jit_check(n_requests: int, full_scale: bool) -> dict:
     return {
         "n_requests": n_requests,
         "asserted": full_scale,
-        "xla_flags": os.environ.get("XLA_FLAGS", ""),
         "seconds_numpy": t_np,
         "seconds_jit_cold": t_cold,
         "seconds_jit_warm": t_warm,

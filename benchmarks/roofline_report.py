@@ -11,7 +11,8 @@ import common  # noqa: F401  -- puts <repo>/src on sys.path
 
 from pathlib import Path
 
-from repro.roofline import analyze_all, format_report
+from repro.roofline import (DRYRUN_DEVICE_KIND, analyze_all, format_report,
+                            peaks_for)
 
 from common import emit  # type: ignore
 
@@ -19,7 +20,7 @@ DRYRUN = Path(__file__).resolve().parent / "results" / "dryrun"
 
 
 def main() -> None:
-    cells = analyze_all(DRYRUN)
+    cells = analyze_all(DRYRUN, peaks_for(DRYRUN_DEVICE_KIND))
     if not cells:
         print("# no dry-run artifacts; run: "
               "PYTHONPATH=src python -m repro.launch.dryrun --all")

@@ -30,9 +30,6 @@ import math
 import time
 from pathlib import Path
 
-# importing common first also selects the legacy XLA:CPU emitter for the
-# vmapped arbitration demo (see common.XLA_THUNK_FLAG -- the single
-# documented knob; bit-identical results, asserted below)
 import common  # noqa: F401  -- puts <repo>/src on sys.path
 
 from repro.multicore import ChipConfig, jitarb  # noqa: E402

@@ -68,18 +68,17 @@ def bench_stream(design: str = "RASA-WLBP", spec_name: str = "BERT-1") -> dict:
     out["numpy_instrs_per_sec"] = n / (time.perf_counter() - t0)
     assert fast.cycles == ref.cycles
 
-    if fastsim.has_jax():
-        cfgs = [get_design(d) for d in
-                ("BASE", "RASA-PIPE", "RASA-WLBP", "RASA-DB-WLS",
-                 "RASA-DM-PIPE", "RASA-DM-WLBP", "RASA-DMDB-WLS",
-                 "RASA-DB-WLBP")]
-        fastsim.sweep_trace(trace, cfgs, backend="jax")    # compile
-        t0 = time.perf_counter()
-        res = fastsim.sweep_trace(trace, cfgs, backend="jax")
-        dt = time.perf_counter() - t0
-        # batched rate: per-design instructions retired per second
-        out["jax_batch8_instrs_per_sec"] = n * len(cfgs) / dt
-        assert abs(res[2].cycles - ref.cycles) <= 1e-6 * ref.cycles
+    cfgs = [get_design(d) for d in
+            ("BASE", "RASA-PIPE", "RASA-WLBP", "RASA-DB-WLS",
+             "RASA-DM-PIPE", "RASA-DM-WLBP", "RASA-DMDB-WLS",
+             "RASA-DB-WLBP")]
+    fastsim.sweep_trace(trace, cfgs, backend="jax")    # compile
+    t0 = time.perf_counter()
+    res = fastsim.sweep_trace(trace, cfgs, backend="jax")
+    dt = time.perf_counter() - t0
+    # batched rate: per-design instructions retired per second
+    out["jax_batch8_instrs_per_sec"] = n * len(cfgs) / dt
+    assert abs(res[2].cycles - ref.cycles) <= 1e-6 * ref.cycles
     return out
 
 
@@ -144,7 +143,6 @@ def run(smoke: bool = False) -> dict:
         "stream": bench_stream(),
         "sweep": bench_sweep(SMOKE_WORKLOAD if smoke else SWEEP_WORKLOAD),
         "multicore": bench_multicore(),
-        "jax_available": fastsim.has_jax(),
         "smoke": smoke,
     }
     write_bench("sim_throughput", table, backend="fast")

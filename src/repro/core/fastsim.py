@@ -21,8 +21,8 @@ exactly a ``jax.lax.scan`` step.  This module implements that step twice:
     (one trace, eight engine configs -- the ``sweep_designs`` fast path) or
     over cores (one config, N per-core traces under a shared epoch-share
     schedule -- the ``multicore`` arbiter fast path).  Runs in float64 via
-    the scoped ``jax.experimental.enable_x64`` context so the global jax
-    configuration is untouched; agrees with the reference to well below
+    the scoped :func:`x64` context so the global jax configuration is
+    untouched; agrees with the reference to well below
     the 1e-6 relative parity bound (see ``tests/test_fastsim.py``).
 
 The load/store arbitration of *both* the paper's idealized port model and
@@ -57,33 +57,28 @@ FAST_JAX_MIN_CORES_INSTRS = 4_000_000
 _BACKENDS = ("fast", "numpy", "jax")
 
 
-@functools.lru_cache(maxsize=1)
-def has_jax() -> bool:
-    try:
-        import jax  # noqa: F401
-        from jax.experimental import enable_x64  # noqa: F401
-    except Exception:
-        return False
-    return True
+def x64():
+    """Scoped float64 for the jax programs (``with x64(): ...``).
+
+    Every jitted simulator program traces, runs and reads back its results
+    inside this context, so it matches the numpy oracle bit for bit while
+    the global jax configuration stays untouched.
+    """
+    import jax
+    return jax.enable_x64(True)
 
 
 def resolve_backend(backend: str, n_instrs: int) -> str:
     """Map a requested backend to a concrete one (``numpy`` or ``jax``).
 
-    ``fast`` auto-selects: jax when it is importable *and* the batch is
-    large enough (>= ``FAST_JAX_MIN_INSTRS`` instructions) to amortize
-    compilation; numpy otherwise.
+    ``fast`` auto-selects: jax when the batch is large enough (>=
+    ``FAST_JAX_MIN_INSTRS`` instructions) to amortize compilation; numpy
+    otherwise.
     """
-    if backend == "numpy":
-        return "numpy"
-    if backend == "jax":
-        if not has_jax():
-            raise RuntimeError("backend='jax' requested but jax is not "
-                               "importable; use backend='numpy' or 'fast'")
-        return "jax"
+    if backend in ("numpy", "jax"):
+        return backend
     if backend == "fast":
-        return "jax" if has_jax() and n_instrs >= FAST_JAX_MIN_INSTRS \
-            else "numpy"
+        return "jax" if n_instrs >= FAST_JAX_MIN_INSTRS else "numpy"
     raise ValueError(f"unknown backend {backend!r}; available: {_BACKENDS} "
                      f"(plus 'reference' at the simulator facade)")
 
@@ -947,7 +942,7 @@ def _sim_chunk_fn(port_model: bool, emit_ends: bool = False):
             start_mem = jnp.where(do_grant, gstart, req)
             done_tl = start_mem + load_lat
             next_free = jnp.where(is_tl, start_mem + inv_load, next_free)
-            ts_tracked = is_ts & ~store_free
+            ts_tracked = is_ts & jnp.logical_not(store_free)
             snext = jnp.where(ts_tracked, start_mem + inv_store, snext)
             start_ts = jnp.where(store_free, t_avail, start_mem)
             stall = jnp.where(
@@ -1451,7 +1446,6 @@ def _port_static(ana: _MMAnalysis, sig) -> tuple[np.ndarray, float]:
 def _sweep_port_mm(trace: CompiledTrace, cfgs: Sequence[EngineConfig],
                    params: StreamModelParams | None) -> list[TimingResult]:
     """The MM-only jax sweep (see section comment above)."""
-    from jax.experimental import enable_x64
     ana = _mm_analysis(trace)
     n_mm = len(ana.mm_pos)
     results: list[TimingResult | None] = [None] * len(cfgs)
@@ -1500,7 +1494,7 @@ def _sweep_port_mm(trace: CompiledTrace, cfgs: Sequence[EngineConfig],
         mem_cfgs = mem_cfgs + [mem_cfgs[-1]] * (B - len(mem_cfgs))
         d = _design_arrays(mem_cfgs)
         design = (d[0], d[1], d[2], d[5], d[6], d[7])   # wl fs dr wlbp wls pipe
-        with enable_x64():
+        with x64():
             carry = _mm_init_carry(B)
             for k in range(n_chunks):
                 sl = slice(k * CHUNK, (k + 1) * CHUNK)
@@ -1535,7 +1529,6 @@ def sweep_trace(trace: CompiledTrace, cfgs: Sequence[EngineConfig],
                     params or StreamModelParams.for_config(cfg))[0]
                 for cfg in cfgs]
 
-    from jax.experimental import enable_x64
     base = params or StreamModelParams(load_ports=1)
     if base.is_port_model and base.store_ports is None:
         return _sweep_port_mm(trace, cfgs, params)
@@ -1549,7 +1542,7 @@ def sweep_trace(trace: CompiledTrace, cfgs: Sequence[EngineConfig],
         [1.0 / (params.load_ports if params is not None else c.load_ports)
          for c in cfgs_p], dtype=np.float64)
     bucket = _bucket_arrays(base, inv_load, np.float64(base.tail_share))
-    with enable_x64():
+    with x64():
         carry = _init_carry(len(cfgs_p), base.burst_bytes)
         t_end, skips, stall, _ = _run_chunked(
             sweep_fn, carry, chunks, idxs, _design_arrays(cfgs_p), bucket)
@@ -1613,7 +1606,6 @@ def _run_cores_jax(traces: Sequence[CompiledTrace], cfg: EngineConfig,
                    params: Sequence[StreamModelParams]
                    ) -> list[tuple[TimingResult, float]]:
     """The jax cores layout for one batch-compatible lane group."""
-    from jax.experimental import enable_x64
     head = params[0]
     cores_fn = _jax_fns(head.is_port_model)[1]
     n = len(traces)
@@ -1622,7 +1614,7 @@ def _run_cores_jax(traces: Sequence[CompiledTrace], cfg: EngineConfig,
     bucket = _bucket_arrays_per_lane(pad_p,
                                      np.float64(1.0 / head.load_ports))
     chunks, idxs = _chunk_batch(lanes)
-    with enable_x64():
+    with x64():
         carry = _init_carry(len(lanes), head.burst_bytes)
         t_end, skips, stall, lg = _run_chunked(
             cores_fn, carry, chunks, idxs, _design_scalars(cfg), bucket)
@@ -1689,7 +1681,6 @@ def sweep_traces(traces: Sequence[CompiledTrace],
                     t, cfg, params or StreamModelParams.for_config(cfg))[0]
                  for cfg in cfgs] for t in traces]
 
-    from jax.experimental import enable_x64
     base = params or StreamModelParams(load_ports=1)
     if base.is_port_model and base.store_ports is None:
         return [_sweep_port_mm(t, cfgs, params) for t in traces]
@@ -1707,7 +1698,7 @@ def sweep_traces(traces: Sequence[CompiledTrace],
         [1.0 / (params.load_ports if params is not None else c.load_ports)
          for c in cfgs_p], dtype=np.float64)
     bucket = _bucket_arrays(base, inv_load, np.float64(base.tail_share))
-    with enable_x64():
+    with x64():
         carry = _init_carry(len(cfgs_p), base.burst_bytes)
         _, ys = _run_chunked(sweep_fn, carry, chunks, idxs,
                              _design_arrays(cfgs_p), bucket, pick=pick)

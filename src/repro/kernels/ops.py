@@ -1,6 +1,6 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Handles: CPU fallback (interpret mode), padding to block multiples, GQA head
+Handles: interpret mode on CPU, padding to block multiples, GQA head
 expansion, and batched (3D+) matmul via vmap-free reshapes.  Models call
 these through ``repro.models.common.matmul`` so the engine is selectable per
 config (``xla`` | ``pallas_rasa``).
@@ -17,8 +17,19 @@ from .flash_attention import flash_attention
 from .rasa_gemm import GemmBlocks, default_blocks, rasa_gemm
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret_default() -> bool:
+    """Interpret mode on the CPU (tests), compiled Mosaic on the TPU.
+
+    Any other backend is an error rather than a silent interpreter run, so a
+    device that is not a TPU is never mistaken for one.
+    """
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"the Pallas kernels run on 'tpu' (or interpreted on "
+                       f"'cpu'), not on {backend!r}")
 
 
 def _pad_to(x: jax.Array, mult: tuple[int, ...]) -> jax.Array:
@@ -37,10 +48,10 @@ def rasa_matmul(a: jax.Array, b: jax.Array, c: jax.Array | None = None,
     """C (+)= A @ B via the RASA-scheduled Pallas kernel, any 2D shapes.
 
     Pads to block multiples (zero padding is exact for matmul) and strips.
-    ``interpret=None`` auto-selects interpret mode off-TPU.
+    ``interpret=None`` interprets on the CPU and compiles on the TPU.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret_default()
     m, k = a.shape
     k2, n = b.shape
     assert k == k2
@@ -67,7 +78,7 @@ def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array,
     query rows; padded q rows are stripped).
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret_default()
     b, hq, sq, d = q.shape
     hkv = k.shape[1]
     assert hq % hkv == 0

@@ -40,8 +40,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import _CompilerParams
-
 SCHEDULES = ("base", "wlbp", "wls")
 
 
@@ -131,7 +129,7 @@ def _ws_call(a: jax.Array, b: jax.Array, c: jax.Array, schedule: str,
         out_specs=c_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         input_output_aliases={0: 0},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(c, a, b)
@@ -174,7 +172,7 @@ def rasa_gemm(a: jax.Array, b: jax.Array, c: jax.Array | None = None,
             out_specs=c_spec,
             out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(a, b, c)

@@ -14,6 +14,7 @@ grid steps).
 
 Layout (heads flattened into the grid):
   x:  [BH, S, P]    dt: [BH, S]    B/C: [BH, S, N]    A: scalar per (b,h)
+  (A sits whole in SMEM; dt enters the kernel as [BH, 1, S] rows.)
 Returns y [BH, S, P] and the final state [BH, P, N].
 """
 
@@ -26,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import _CompilerParams
-
 
 def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, fin_ref,
                 state_ref, *, chunk: int):
@@ -37,35 +36,39 @@ def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, fin_ref,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    a = a_ref[0]                                   # scalar decay rate (<0)
+    # Per-step vectors are kept 2-D (a [1, q] row or a [q, 1] column): the
+    # TPU tiles the last two dims, so 1-D blocks and a cumsum do not lower.
+    a = a_ref[pl.program_id(0)]                    # scalar decay rate (<0)
     x = x_ref[0].astype(jnp.float32)               # [q, P]
-    dt = dt_ref[0].astype(jnp.float32)             # [q]
+    dt = dt_ref[0].astype(jnp.float32)             # [1, q]
     b = b_ref[0].astype(jnp.float32)               # [q, N]
     c = c_ref[0].astype(jnp.float32)               # [q, N]
 
-    dA = dt * a                                    # [q] (negative)
-    seg = jnp.cumsum(dA)                           # [q]
-    xdt = x * dt[:, None]                          # [q, P]
-
-    # intra-chunk: scores[i,j] = c_i.b_j * exp(seg_i - seg_j), i >= j
-    cb = jnp.dot(c, b.T, preferred_element_type=jnp.float32)   # [q, q]
-    diff = seg[:, None] - seg[None, :]
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    diff = jnp.where(ii >= jj, diff, -1e30)
-    w = cb * jnp.exp(diff)                         # [q, q]
-    y = jnp.dot(w, xdt, preferred_element_type=jnp.float32)    # [q, P]
+    dA = dt * a                                    # [1, q] (negative)
+    dA_col = jnp.sum(jnp.where(ii == jj, dA, 0.0), axis=1, keepdims=True)
+    seg = jnp.sum(jnp.where(ii <= jj, dA_col, 0.0), axis=0,
+                  keepdims=True)                   # [1, q] inclusive cumsum
+    seg_col = jnp.sum(jnp.where(ii == jj, seg, 0.0), axis=1,
+                      keepdims=True)               # [q, 1], the same values
+    seg_last = jnp.sum(dA, axis=1, keepdims=True)  # [1, 1]
+
+    # intra-chunk: scores[i,j] = c_i.b_j * exp(seg_i - seg_j) * dt_j, i >= j
+    cb = jnp.dot(c, b.T, preferred_element_type=jnp.float32)   # [q, q]
+    diff = jnp.where(ii >= jj, seg_col - seg, -1e30)
+    w = cb * jnp.exp(diff) * dt                    # [q, q]
+    y = jnp.dot(w, x, preferred_element_type=jnp.float32)      # [q, P]
 
     # inter-chunk: y_i += (c_i . state_prev) * exp(seg_i)
     prev = state_ref[...]                          # [N, P]
     y = y + jnp.dot(c, prev,
-                    preferred_element_type=jnp.float32) * jnp.exp(seg)[:, None]
+                    preferred_element_type=jnp.float32) * jnp.exp(seg_col)
 
-    # state update: state = exp(seg_last)*prev + sum_j b_j (xdt_j)^T decay_j
-    wj = jnp.exp(seg[-1] - seg)                    # [q]
-    st_c = jnp.dot((b * wj[:, None]).T, xdt,
-                   preferred_element_type=jnp.float32)         # [N, P]
-    state_ref[...] = prev * jnp.exp(seg[-1]) + st_c
+    # state update: state = exp(seg_last)*prev + sum_j b_j (dt_j x_j)^T decay_j
+    wj = jnp.exp(seg_last - seg) * dt              # [1, q]
+    st_c = jnp.dot(b.T * wj, x, preferred_element_type=jnp.float32)  # [N, P]
+    state_ref[...] = prev * jnp.exp(seg_last) + st_c
 
     y_ref[0] = y.astype(y_ref.dtype)
 
@@ -92,9 +95,9 @@ def ssd_chunk_fused(x: jax.Array, dt: jax.Array, a: jax.Array,
         kernel,
         grid=(bh, nc),
         in_specs=[
-            pl.BlockSpec((1,), lambda i, j: (i,)),             # a
+            pl.BlockSpec(memory_space=pltpu.SMEM),             # a, whole
             pl.BlockSpec((1, chunk, p), lambda i, j: (i, j, 0)),   # x
-            pl.BlockSpec((1, chunk), lambda i, j: (i, j)),     # dt
+            pl.BlockSpec((1, 1, chunk), lambda i, j: (i, 0, j)),   # dt
             pl.BlockSpec((1, chunk, n), lambda i, j: (i, j, 0)),   # b
             pl.BlockSpec((1, chunk, n), lambda i, j: (i, j, 0)),   # c
         ],
@@ -107,10 +110,10 @@ def ssd_chunk_fused(x: jax.Array, dt: jax.Array, a: jax.Array,
             jax.ShapeDtypeStruct((bh, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(a, x, dt, b, c)
+    )(a, x, dt.reshape(bh, 1, s), b, c)
     return y, fin
 
 

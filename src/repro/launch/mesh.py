@@ -13,13 +13,9 @@ from ..config import ParallelConfig
 
 
 def _auto_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types; older jax (< 0.6, no
-    jax.sharding.AxisType) builds auto-sharded meshes unconditionally."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with Auto axis types."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

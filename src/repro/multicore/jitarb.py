@@ -103,7 +103,7 @@ from typing import Sequence
 
 import numpy as np
 
-from ..core.fastsim import (_design_arrays, _pow2, has_jax, run_segment)
+from ..core.fastsim import _design_arrays, _pow2, run_segment, x64
 from ..core.isa import NUM_TREGS
 from ..core.tiling import GemmSpec
 from ..core.trace import OP_NOP, CompiledTrace, compiled_trace
@@ -131,7 +131,6 @@ _KMAX_CAP = 64
 #: vocabulary); ``None`` means the trace jitted
 GATE_REASONS = (
     "no_requests",          # empty trace: nothing to settle
-    "no_jax",               # jax is not importable in this environment
     "backend",              # chip.backend != "jax"
     "arbitration",          # only the epoch arbiter is lowered
     "faults_active",        # fault plans replay host-side only
@@ -230,8 +229,6 @@ def plan_ex(traffic: Sequence[tuple[int, Sequence[GemmSpec]]],
     """
     if not traffic:
         return None, "no_requests"
-    if not has_jax():
-        return None, "no_jax"
     if chip.backend != "jax":
         return None, "backend"
     if chip.arbitration != "epoch":
@@ -941,11 +938,9 @@ def finish_admit_times(p: Plan, stats: dict | None = None
     simulated-block counters are recorded into it (benchmark
     diagnostics).
     """
-    from jax.experimental import enable_x64
-
     statics, arrays = _launch_args(p)
     fn = _kernel(*statics)[0]
-    with enable_x64():
+    with x64():
         fin, adm, mxn, n_r, n_b = fn(p.cols, p.tr_len, p.t2l, p.wt,
                                      p.est, p.arrival, p.qidx, p.qsub,
                                      p.qtail0, p.tid_of, *arrays)
@@ -971,12 +966,10 @@ def finish_times(p: Plan, stats: dict | None = None) -> np.ndarray:
 def finish_times_many(plans: Sequence[Plan]) -> list[np.ndarray]:
     """Run a family of same-shape plans (e.g. an arrival-rate sweep) as
     one vmapped launch.  All plans must come from :func:`plan_many`."""
-    from jax.experimental import enable_x64
-
     head = plans[0]
     statics, arrays = _launch_args(head)
     fn = _kernel(*statics)[1]
-    with enable_x64():
+    with x64():
         fin, _, mxn, _, _ = fn(head.cols, head.tr_len, head.t2l, head.wt,
                                head.est,
                                np.stack([p.arrival for p in plans]),

@@ -18,7 +18,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def compress_int8(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -55,12 +54,12 @@ def compressed_psum(grads: Any, residuals: Any, mesh: Mesh,
     (summed grads fp32, new residuals).
     """
     def one(g, r):
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(_psum_one, axis_names=axis_names),
             mesh=mesh,
             in_specs=(spec or P(), spec or P()),
             out_specs=(spec or P(), spec or P()),
-            check_rep=False)
+            check_vma=False)
         return fn(g, r)
 
     pairs = jax.tree.map(one, grads, residuals)

@@ -1,7 +1,7 @@
 """Roofline analysis from compiled dry-run artifacts."""
 
-from .analysis import (HW, CellRoofline, analyze_cell, analyze_all,
-                       format_report)
+from .analysis import (DRYRUN_DEVICE_KIND, HW, PEAKS, CellRoofline,
+                       analyze_all, analyze_cell, format_report, peaks_for)
 
-__all__ = ["HW", "CellRoofline", "analyze_cell", "analyze_all",
-           "format_report"]
+__all__ = ["DRYRUN_DEVICE_KIND", "HW", "PEAKS", "CellRoofline",
+           "analyze_cell", "analyze_all", "format_report", "peaks_for"]

@@ -16,8 +16,8 @@ where a "unit" is one scanned layer (transformers/ssm) or one group of
 full-depth artifact (memory/sharding proof) and the reduced-depth artifacts
 (flops/bytes/collectives); this module combines them.
 
-Hardware constants (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (per direction).
+Hardware constants come from :data:`PEAKS`, keyed by the device kind jax
+reports; a device missing from the table is an error, never a default.
 """
 
 from __future__ import annotations
@@ -31,13 +31,33 @@ from ..config import SHAPES
 
 @dataclasses.dataclass(frozen=True)
 class HW:
-    peak_flops: float = 197e12         # bf16 / chip
-    hbm_bw: float = 819e9              # bytes/s / chip
-    ici_bw: float = 50e9               # bytes/s / link
-    hbm_bytes: float = 16 * 2**30      # v5e HBM capacity
+    peak_flops: float                  # bf16 / chip
+    hbm_bw: float                      # bytes/s / chip
+    ici_bw: float                      # bytes/s / link
+    hbm_bytes: float                   # HBM capacity / chip
 
 
-V5E = HW()
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``.  TPU v5e
+#: (reported as "TPU v5 lite"): Google Cloud documentation, "TPU v5e" --
+#: 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip over
+#: four links (~50 GB/s per link and direction).
+PEAKS: dict[str, HW] = {
+    "TPU v5 lite": HW(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                      hbm_bytes=16 * 2**30),
+}
+
+#: the device the dry-run compiles for (``repro.launch.dryrun``: a 16x16
+#: v5e pod per pod)
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks_for(device_kind: str) -> HW:
+    """The :data:`PEAKS` entry for ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 COLL_OPS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
             "collective-permute")
@@ -57,8 +77,10 @@ class CellRoofline:
     memory_s: float = 0.0
     collective_s: float = 0.0
     extrapolated: bool = False
+    hw: HW | None = None               # the peaks the terms were taken at
 
-    def finalize(self, hw: HW = V5E) -> "CellRoofline":
+    def finalize(self, hw: HW) -> "CellRoofline":
+        self.hw = hw
         self.compute_s = self.flops_per_device / hw.peak_flops
         self.memory_s = self.bytes_per_device / hw.hbm_bw
         self.collective_s = self.coll_bytes_per_device / hw.ici_bw
@@ -85,7 +107,7 @@ class CellRoofline:
     @property
     def mfu(self) -> float:
         """Model FLOPs utilization at the roofline step time."""
-        denom = self.step_time_s * self.devices * V5E.peak_flops
+        denom = self.step_time_s * self.devices * self.hw.peak_flops
         return self.model_flops / denom if denom else 0.0
 
 
@@ -121,7 +143,7 @@ def _coll_sum(cell: dict) -> float:
     return sum(v for k, v in colls.items() if not k.endswith("_count"))
 
 
-def analyze_cell(cell: dict, hw: HW = V5E,
+def analyze_cell(cell: dict, hw: HW,
                  d0: dict | None = None, du: dict | None = None) -> CellRoofline:
     """Roofline terms for one cell.  With the reduced-depth unrolled
     artifacts (d0 = embed+head only, du = one unit of layers), totals are
@@ -162,7 +184,8 @@ def _load_depth(results_dir: Path, arch: str, shape: str, depth: int) -> dict | 
     return json.loads(p.read_text()) if p.exists() else None
 
 
-def analyze_all(results_dir: str | Path, multi_pod: bool = False) -> list[CellRoofline]:
+def analyze_all(results_dir: str | Path, hw: HW,
+                multi_pod: bool = False) -> list[CellRoofline]:
     results_dir = Path(results_dir)
     from ..configs import all_cells, get_config
     out = []
@@ -173,11 +196,11 @@ def analyze_all(results_dir: str | Path, multi_pod: bool = False) -> list[CellRo
         unit = cell.get("unit_layers", 1)
         d0 = _load_depth(results_dir, arch, shape, 0)
         du = _load_depth(results_dir, arch, shape, unit)
-        out.append(analyze_cell(cell, d0=d0, du=du))
+        out.append(analyze_cell(cell, hw, d0=d0, du=du))
     return out
 
 
-def format_report(cells: list[CellRoofline], hw: HW = V5E) -> str:
+def format_report(cells: list[CellRoofline]) -> str:
     hdr = (f"{'arch':24s} {'shape':12s} {'compute_s':>10s} {'memory_s':>10s} "
            f"{'coll_s':>10s} {'bound':>10s} {'mem_GiB':>8s} {'MFU%':>6s} "
            f"{'useful%':>8s}")

@@ -135,12 +135,16 @@ class ServeSession:
         self._compiled[key] = fns
         return fns
 
+    def prefill(self, prompts: jax.Array) -> tuple[jax.Array, Any]:
+        """prompts: [B, S] int32 -> (last-position logits, decode state)."""
+        b = prompts.shape[0]
+        state = self.api.init_decode_state(b, self.max_seq)
+        return self._fns(b)[0](self.params, prompts, state)
+
     def generate(self, prompts: jax.Array, steps: int) -> jax.Array:
         """prompts: [B, S] int32 -> generated tokens [B, steps]."""
-        b = prompts.shape[0]
-        prefill, decode = self._fns(b)
-        state = self.api.init_decode_state(b, self.max_seq)
-        logits, state = prefill(self.params, prompts, state)
+        decode = self._fns(prompts.shape[0])[1]
+        logits, state = self.prefill(prompts)
         outs = []
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         for _ in range(steps):

@@ -18,7 +18,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _local_decode(q, k, v, start, lengths, scale):
@@ -75,8 +74,8 @@ def sp_flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      and h % mesh.shape["model"] == 0) else None
     spec_q = P(None, hm, None)
     spec_kv = P(None, hm, seq_axis, None)
-    fn = shard_map(shard_fn, mesh=mesh,
+    fn = jax.shard_map(shard_fn, mesh=mesh,
                    in_specs=(spec_q, spec_kv, spec_kv, P()),
                    out_specs=spec_q,
-                   check_rep=False)
+                   check_vma=False)
     return fn(q, k_cache, v_cache, lengths)

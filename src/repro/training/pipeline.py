@@ -27,7 +27,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(stage_params: Any, x: jax.Array,
@@ -83,11 +82,11 @@ def pipeline_apply(stage_params: Any, x: jax.Array,
         y = jax.lax.psum(contrib, axis)
         return y
 
-    y_mb = shard_map(
+    y_mb = jax.shard_map(
         staged, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, x_mb)
     return y_mb.reshape(b, *y_mb.shape[2:])
 

@@ -11,9 +11,8 @@ from collections import defaultdict
 
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core import GemmSpec, TABLE_I, simulate
-from repro.core import fastsim
 from repro.core.timing import PipelineSimulator
 from repro.multicore import (ChipConfig, CoreSpec, DemandWeightedShare,
                              EpochBandwidthLoadModel, OnlineChip,
@@ -28,7 +27,7 @@ SMALL = GemmSpec("small", 128, 256, 256)
 BIG = GemmSpec("big", 256, 768, 768)
 
 #: backends every end-to-end scenario must agree on
-BACKENDS = ["reference", "numpy"] + (["jax"] if fastsim.has_jax() else [])
+BACKENDS = ["reference", "numpy", "jax"]
 
 
 def _skewed_workload():

@@ -10,10 +10,9 @@ import random
 
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core import (DESIGNS, GemmSpec, Instr, Op, TABLE_I, get_design,
                         simulate, sweep_designs, sweep_workload)
-from repro.core import fastsim
 from repro.core.fastsim import (StreamModelParams, _run_numpy_params,
                                 run_cores, run_trace_numpy, sweep_trace)
 from repro.core.simulator import _simulate_cached
@@ -22,9 +21,6 @@ from repro.core.timing import LoadStreamModel, PipelineSimulator
 from repro.core.trace import compile_stream, compiled_trace, gemm_trace
 from repro.multicore import ChipConfig, simulate_chip
 from repro.multicore.chip import EpochBandwidthLoadModel
-
-needs_jax = pytest.mark.skipif(not fastsim.has_jax(),
-                               reason="jax not importable")
 
 SMALL = GemmSpec("small", 128, 256, 256)
 REL = 1e-6          # the acceptance bound; numpy is in fact bit-exact
@@ -118,7 +114,6 @@ def test_numpy_parity_random_streams(seed):
     _check_stream(random_stream(random.Random(seed), 120))
 
 
-@needs_jax
 @pytest.mark.parametrize("seed", [0, 5])
 def test_jax_parity_random_streams(seed):
     """jax scan parity on random streams (two designs to bound compiles)."""
@@ -159,8 +154,7 @@ def test_static_reuse_bits_match_dirty_bit_tracking():
 
 
 # ------------------------------------------------------------ GEMM parity
-@pytest.mark.parametrize("backend", ["numpy"] +
-                         (["jax"] if fastsim.has_jax() else []))
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
 def test_simulate_backend_parity(backend):
     ref = simulate(SMALL, "RASA-DMDB-WLS")
     fast = simulate(SMALL, "RASA-DMDB-WLS", backend=backend)
@@ -169,8 +163,7 @@ def test_simulate_backend_parity(backend):
     assert fast.utilization == pytest.approx(ref.utilization, rel=REL)
 
 
-@pytest.mark.parametrize("backend", ["numpy"] +
-                         (["jax"] if fastsim.has_jax() else []))
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
 def test_sweep_designs_backend_parity(backend):
     ref = sweep_designs(SMALL)
     fast = sweep_designs(SMALL, backend=backend)
@@ -180,7 +173,6 @@ def test_sweep_designs_backend_parity(backend):
         assert fast[k].wl_skips == ref[k].wl_skips, k
 
 
-@needs_jax
 def test_sweep_workload_grid_parity():
     wl = [SMALL, TABLE_I["DLRM-2"], GemmSpec("odd", 200, 96, 150)]
     ref = sweep_workload(wl)
@@ -241,8 +233,7 @@ def _skewed():
 
 
 @pytest.mark.parametrize("arbitration", ["static", "epoch"])
-@pytest.mark.parametrize("backend", ["numpy"] +
-                         (["jax"] if fastsim.has_jax() else []))
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
 def test_chip_backend_parity(arbitration, backend):
     """run_streams fixed point: fast backends match the reference chip
     simulation -- makespan, stalls, arbiter trace -- under a binding
@@ -280,7 +271,7 @@ def test_run_cores_epoch_parity_with_last_grant():
                                     2048.0, 1, True)
         r = PipelineSimulator(cfg, load_model=m).run(s)
         refs.append((r, m.last_grant))
-    backends = ["numpy"] + (["jax"] if fastsim.has_jax() else [])
+    backends = ["numpy", "jax"]
     for be in backends:
         for (rr, rlg), (fr, flg) in zip(
                 refs, run_cores(traces, cfg, params, backend=be)):

@@ -11,7 +11,7 @@ import pickle
 
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core import ALG1_POLICY, GemmSpec, TABLE_I
 from repro.core.fastsim import completed_prefix
 from repro.core.tiling import lower_gemm

@@ -5,7 +5,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import (GemmBlocks, SCHEDULES, flash_mha, rasa_matmul,
                            schedule_cost, default_blocks)
@@ -142,3 +142,15 @@ def test_decode_attention_ref_consistency():
     dec = ref_decode_attention(q[:, :, 0], k, v)
     np.testing.assert_allclose(np.asarray(full[:, :, 0]), np.asarray(dec),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_interpret_only_on_cpu(monkeypatch):
+    """Left to choose, the kernels interpret on the CPU and compile on the
+    TPU; any other backend is refused rather than silently interpreted."""
+    from repro.kernels import ops
+    assert ops._interpret_default() is True          # this suite: CPU
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert ops._interpret_default() is False
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="not on 'gpu'"):
+        ops._interpret_default()

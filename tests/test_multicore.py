@@ -9,7 +9,7 @@ from collections import defaultdict
 
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core import DESIGNS, GemmSpec, TABLE_I, simulate
 from repro.core.designs import get_design
 from repro.core.engine import simulate_chip as core_simulate_chip

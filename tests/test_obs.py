@@ -287,6 +287,26 @@ def test_bench_envelope_validation(tmp_path):
     assert any("unreadable" in e for e in common.validate_bench(broken))
 
 
+def test_cache_json_keyed_on_device(tmp_path, monkeypatch):
+    """A cached benchmark result is served only on the platform and device
+    kind it was computed on: a CPU result copied with the tree is
+    recomputed on the chip."""
+    common = _bench_common()
+    monkeypatch.setattr(common, "RESULTS", tmp_path)
+    calls = []
+
+    def compute():
+        calls.append(1)
+        return {"n": len(calls)}
+
+    assert common.cache_json("k", compute, fingerprint="f") == {"n": 1}
+    assert common.cache_json("k", compute, fingerprint="f") == {"n": 1}
+    assert common.cache_json("k", compute, fingerprint="g") == {"n": 2}
+    monkeypatch.setattr(common, "device_key", lambda: "tpu/TPU v5 lite")
+    assert common.cache_json("k", compute, fingerprint="g") == {"n": 3}
+    assert len(calls) == 3
+
+
 def test_load_stall_cycles_deprecated_alias():
     """The pre-PR-6 name keeps working on both result types."""
     res = simulate(GemmSpec("alias", 32, 128, 128), "RASA-WLBP")

@@ -32,16 +32,13 @@ import dataclasses
 
 import pytest
 
-from _hypothesis_compat import given, settings, st
-from repro.core.fastsim import has_jax
+from hypothesis import given, settings, strategies as st
 from repro.multicore import ChipConfig
 from repro.multicore.faults import FaultPlan, core_down, core_up
 from repro.multicore.jitarb import (finish_admit_times, finish_times_many,
                                     plan, plan_ex, plan_many)
 from repro.serving.simbatch import (model_trace, report_from_finishes,
                                     run_batcher, synthetic_trace)
-
-pytestmark = pytest.mark.skipif(not has_jax(), reason="jax not installed")
 
 ALL_DESIGNS = ("BASE", "RASA-DB-WLBP", "RASA-DB-WLS", "RASA-DM-PIPE",
                "RASA-DM-WLBP", "RASA-DMDB-WLS", "RASA-PIPE", "RASA-WLBP")

@@ -9,7 +9,7 @@ import math
 
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core import GemmSpec, simulate
 from repro.multicore import ChipConfig, OnlineChip
 from repro.serving.simbatch import (POLICIES, run_batcher, skewed_trace,

@@ -5,7 +5,10 @@ import json
 import pytest
 
 from repro.launch.dryrun import parse_collectives
-from repro.roofline.analysis import (HW, V5E, analyze_cell, model_flops_for)
+from repro.roofline.analysis import (DRYRUN_DEVICE_KIND, analyze_cell,
+                                     model_flops_for, peaks_for)
+
+V5E = peaks_for(DRYRUN_DEVICE_KIND)
 
 
 def test_parse_collectives_sums_operand_bytes():
@@ -40,7 +43,7 @@ def _cell(flops=1e12, byts=1e11, coll=1e9, devices=256, unit=1, total=10):
 
 
 def test_analyze_cell_terms():
-    r = analyze_cell(_cell(flops=1e14))
+    r = analyze_cell(_cell(flops=1e14), V5E)
     assert r.compute_s == pytest.approx(1e14 / V5E.peak_flops)
     assert r.memory_s == pytest.approx(1e11 / V5E.hbm_bw)
     assert r.collective_s == pytest.approx(1e9 / V5E.ici_bw)
@@ -52,7 +55,7 @@ def test_analyze_cell_depth_extrapolation():
     base = _cell()
     d0 = _cell(flops=2e10, byts=1e9, coll=1e8)
     du = _cell(flops=3e10, byts=2e9, coll=3e8)
-    r = analyze_cell(base, d0=d0, du=du)
+    r = analyze_cell(base, V5E, d0=d0, du=du)
     assert r.extrapolated
     # total = d0 + 10 * (du - d0)
     assert r.flops_per_device == pytest.approx(2e10 + 10 * 1e10)
@@ -60,7 +63,7 @@ def test_analyze_cell_depth_extrapolation():
 
 
 def test_dominant_collective():
-    r = analyze_cell(_cell(flops=1e9, byts=1e9, coll=1e12))
+    r = analyze_cell(_cell(flops=1e9, byts=1e9, coll=1e12), V5E)
     assert r.dominant == "collective"
 
 
@@ -76,3 +79,11 @@ def test_model_flops_conventions():
     grok = model_flops_for("grok-1-314b", "train_4k")
     n_active = get_config("grok-1-314b").model.active_param_count()
     assert grok == pytest.approx(6.0 * n_active * 4096 * 256)
+
+
+def test_unknown_device_kind_has_no_peaks():
+    """Peaks are keyed by device kind; a device missing from the table is
+    an error, never a silent v5e default."""
+    assert V5E.peak_flops == 197e12 and V5E.hbm_bw == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks_for("cpu")

@@ -5,7 +5,7 @@ import math
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (ALG1_POLICY, MAX_REUSE_POLICY, GemmSpec, Op,
                         RegPolicy, count_ops, lower_gemm, stream_stats,

@@ -1,7 +1,7 @@
 """Cycle-model tests: every number the paper states, plus pipeline invariants."""
 
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (DESIGNS, Instr, Op, get_design,
                         steady_state_interval)
