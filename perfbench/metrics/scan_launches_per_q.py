@@ -1,0 +1,8 @@
+"""Device program executions in the traced window per question answered."""
+
+
+def read(run: dict) -> float | None:
+    dev = run["trace"]
+    if dev is None or not run["questions"]:
+        return None
+    return dev["launches"] / run["questions"]
