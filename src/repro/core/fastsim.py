@@ -41,6 +41,7 @@ from typing import Sequence
 
 import numpy as np
 
+from . import probe
 from .designs import EngineConfig
 from .isa import NUM_TREGS
 from .timing import LoadStreamModel, TimingResult
@@ -1075,28 +1076,47 @@ def _init_carry(n_lanes: int, burst: float):
             jnp.asarray(np.full(n_lanes, burst, dtype=f)), jnp.asarray(z))
 
 
+def _host_bytes(tree) -> int:
+    """Bytes of the numpy leaves of ``tree``: what a jitted call copies
+    from the host to the device."""
+    import jax
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(tree)
+               if isinstance(x, (np.ndarray, np.generic)))
+
+
 def _run_chunked(fn, carry, trace_chunks, idx_chunks, design, bucket,
-                 pick=None):
+                 useful, pick=None):
     """Thread the batched carry through one jitted chunk call per chunk.
 
-    ``pick`` (one int array per chunk) selects per-step emission positions
-    to keep -- the OP_END markers of a packed stream.  Only those slices are
-    retained (lazily), so the chunk chain stays async and the full [B, L]
-    emission buffers are never materialized on the host.
+    ``useful`` is the number of real (instruction, lane) steps among the
+    scanned ones, for the ``sim.useful_steps`` counter.  ``pick`` (one int
+    array per chunk) selects per-step emission positions to keep -- the
+    OP_END markers of a packed stream.  Only those slices are retained
+    (lazily), so the chunk chain stays async and the full [B, L] emission
+    buffers are never materialized on the host.
     """
     kept = []
-    for k, (xs, idx) in enumerate(zip(trace_chunks, idx_chunks)):
-        carry, ys = fn(carry, xs, idx, design, bucket)
-        if pick is not None and len(pick[k]):
-            kept.append(tuple(y[..., pick[k]] for y in ys))
-    outs = [np.asarray(carry[s]) for s in _OUT_SLOTS]
-    if pick is None:
-        return outs
-    if not kept:
-        empty = np.zeros((0,))
-        return outs, [empty] * len(_OUT_SLOTS)
-    cat = [np.concatenate([np.asarray(y[k]) for y in kept], axis=-1)
-           for k in range(len(_OUT_SLOTS))]
+    n_chunks = len(trace_chunks)
+    # the initial carry was copied from the host when it was made
+    h2d = sum(c.nbytes for c in carry) + sum(
+        _host_bytes((xs, idx, design, bucket))
+        for xs, idx in zip(trace_chunks, idx_chunks))
+    with probe.span("sim.dispatch", chunk_calls=n_chunks,
+                    scan_steps=n_chunks * CHUNK * len(carry[7]),
+                    useful_steps=useful, h2d_bytes=h2d):
+        for k, (xs, idx) in enumerate(zip(trace_chunks, idx_chunks)):
+            carry, ys = fn(carry, xs, idx, design, bucket)
+            if pick is not None and len(pick[k]):
+                kept.append(tuple(y[..., pick[k]] for y in ys))
+    with probe.span("sim.wait"):
+        outs = [np.asarray(carry[s]) for s in _OUT_SLOTS]
+        if pick is None:
+            return outs
+        if not kept:
+            empty = np.zeros((0,))
+            return outs, [empty] * len(_OUT_SLOTS)
+        cat = [np.concatenate([np.asarray(y[k]) for y in kept], axis=-1)
+               for k in range(len(_OUT_SLOTS))]
     return outs, cat
 
 
@@ -1446,66 +1466,84 @@ def _port_static(ana: _MMAnalysis, sig) -> tuple[np.ndarray, float]:
 def _sweep_port_mm(trace: CompiledTrace, cfgs: Sequence[EngineConfig],
                    params: StreamModelParams | None) -> list[TimingResult]:
     """The MM-only jax sweep (see section comment above)."""
-    ana = _mm_analysis(trace)
+    with probe.span("sim.analyse"):
+        ana = _mm_analysis(trace)
+        groups: dict[tuple, list[int]] = {}
+        for j, cfg in enumerate(cfgs):
+            groups.setdefault(_load_sig(cfg, params), []).append(j)
     n_mm = len(ana.mm_pos)
     results: list[TimingResult | None] = [None] * len(cfgs)
-    groups: dict[tuple, list[int]] = {}
-    for j, cfg in enumerate(cfgs):
-        groups.setdefault(_load_sig(cfg, params), []).append(j)
     fn = _jax_mm_fn()
     for sig, members in groups.items():
-        done_tl, static_end = _port_static(ana, sig)
+        with probe.span("sim.analyse"):
+            done_tl, static_end = _port_static(ana, sig)
         if n_mm == 0:
-            for j in members:
-                results[j] = _result(trace, cfgs[j], static_end, 0, 0.0)
+            with probe.span("sim.report"):
+                for j in members:
+                    results[j] = _result(trace, cfgs[j], static_end, 0, 0.0)
             continue
-        issue = sig[0]
-
-        def const_of(kind, tl_idx):
-            if len(done_tl):
-                v = done_tl[tl_idx]
-            else:
-                v = np.zeros(len(tl_idx), dtype=np.float64)
-            return np.where(kind == 1, v, 0.0)
-
         n_chunks = -(-n_mm // CHUNK)
-        L = n_chunks * CHUNK
-        pad = L - n_mm
-
-        def padded(arr, fill=0):
-            return np.concatenate(
-                [arr, np.full(pad, fill, dtype=arr.dtype)])
-
-        f64 = np.float64
-        cols = (padded(np.ones(n_mm, dtype=bool)),
-                padded(ana.c), padded(ana.a), padded(ana.b),
-                padded(ana.a_kind == 2), padded(ana.b_kind == 2),
-                padded(ana.c_kind == 2),
-                padded(const_of(ana.a_kind, ana.a_tl).astype(f64)),
-                padded(const_of(ana.b_kind, ana.b_tl).astype(f64)),
-                padded(const_of(ana.c_kind, ana.c_tl).astype(f64)),
-                padded(ana.reusable), padded(ana.tm),
-                padded((ana.mm_pos / issue).astype(f64)),
-                padded(ana.ts_max_pos >= 0),
-                padded(np.where(ana.ts_max_pos >= 0,
-                                ana.ts_max_pos / issue, 0.0).astype(f64)))
-        mem_cfgs = [cfgs[j] for j in members]
-        B = _pow2(len(mem_cfgs), lo=1)
-        mem_cfgs = mem_cfgs + [mem_cfgs[-1]] * (B - len(mem_cfgs))
-        d = _design_arrays(mem_cfgs)
-        design = (d[0], d[1], d[2], d[5], d[6], d[7])   # wl fs dr wlbp wls pipe
+        B = _pow2(len(members), lo=1)
         with x64():
-            carry = _mm_init_carry(B)
-            for k in range(n_chunks):
-                sl = slice(k * CHUNK, (k + 1) * CHUNK)
-                carry = fn(carry, tuple(col[sl] for col in cols), design)
-            t_end = np.asarray(carry[7])
-            skips = np.asarray(carry[8])
-        for bi, j in enumerate(members):
-            results[j] = _result(trace, cfgs[j],
-                                 max(float(t_end[bi]), static_end),
-                                 int(skips[bi]), 0.0)
+            with probe.span("sim.stage"):
+                cols = _mm_columns(ana, done_tl, sig[0], n_chunks * CHUNK)
+                mem_cfgs = [cfgs[j] for j in members]
+                d = _design_arrays(mem_cfgs
+                                   + [mem_cfgs[-1]] * (B - len(mem_cfgs)))
+                # wl fs dr wlbp wls pipe
+                design = (d[0], d[1], d[2], d[5], d[6], d[7])
+                carry = _mm_init_carry(B)
+            # every chunk call copies its column slices and the designs
+            per_call = (sum(c.itemsize for c in cols) * CHUNK
+                        + _host_bytes(design))
+            with probe.span("sim.dispatch", chunk_calls=n_chunks,
+                            scan_steps=n_chunks * CHUNK * B,
+                            useful_steps=n_mm * len(members),
+                            h2d_bytes=(sum(c.nbytes for c in carry)
+                                       + n_chunks * per_call)):
+                for k in range(n_chunks):
+                    sl = slice(k * CHUNK, (k + 1) * CHUNK)
+                    carry = fn(carry, tuple(col[sl] for col in cols), design)
+            with probe.span("sim.wait"):
+                t_end = np.asarray(carry[7])
+                skips = np.asarray(carry[8])
+        with probe.span("sim.report"):
+            for bi, j in enumerate(members):
+                results[j] = _result(trace, cfgs[j],
+                                     max(float(t_end[bi]), static_end),
+                                     int(skips[bi]), 0.0)
     return results  # type: ignore[return-value]
+
+
+def _mm_columns(ana: _MMAnalysis, done_tl: np.ndarray, issue: float,
+                L: int) -> tuple[np.ndarray, ...]:
+    """The 15 per-MM scan columns of one load-signature group, padded
+    with invalid steps to ``L``."""
+    n_mm = len(ana.mm_pos)
+
+    def const_of(kind, tl_idx):
+        if len(done_tl):
+            v = done_tl[tl_idx]
+        else:
+            v = np.zeros(len(tl_idx), dtype=np.float64)
+        return np.where(kind == 1, v, 0.0)
+
+    def padded(arr, fill=0):
+        return np.concatenate([arr, np.full(L - n_mm, fill, dtype=arr.dtype)])
+
+    f64 = np.float64
+    return (padded(np.ones(n_mm, dtype=bool)),
+            padded(ana.c), padded(ana.a), padded(ana.b),
+            padded(ana.a_kind == 2), padded(ana.b_kind == 2),
+            padded(ana.c_kind == 2),
+            padded(const_of(ana.a_kind, ana.a_tl).astype(f64)),
+            padded(const_of(ana.b_kind, ana.b_tl).astype(f64)),
+            padded(const_of(ana.c_kind, ana.c_tl).astype(f64)),
+            padded(ana.reusable), padded(ana.tm),
+            padded((ana.mm_pos / issue).astype(f64)),
+            padded(ana.ts_max_pos >= 0),
+            padded(np.where(ana.ts_max_pos >= 0,
+                            ana.ts_max_pos / issue, 0.0).astype(f64)))
 
 
 def sweep_trace(trace: CompiledTrace, cfgs: Sequence[EngineConfig],
@@ -1545,7 +1583,8 @@ def sweep_trace(trace: CompiledTrace, cfgs: Sequence[EngineConfig],
     with x64():
         carry = _init_carry(len(cfgs_p), base.burst_bytes)
         t_end, skips, stall, _ = _run_chunked(
-            sweep_fn, carry, chunks, idxs, _design_arrays(cfgs_p), bucket)
+            sweep_fn, carry, chunks, idxs, _design_arrays(cfgs_p), bucket,
+            len(trace) * n)
     return [_result(trace, cfg, t_end[b], skips[b], stall[b])
             for b, cfg in enumerate(cfgs)]
 
@@ -1617,7 +1656,8 @@ def _run_cores_jax(traces: Sequence[CompiledTrace], cfg: EngineConfig,
     with x64():
         carry = _init_carry(len(lanes), head.burst_bytes)
         t_end, skips, stall, lg = _run_chunked(
-            cores_fn, carry, chunks, idxs, _design_scalars(cfg), bucket)
+            cores_fn, carry, chunks, idxs, _design_scalars(cfg), bucket,
+            sum(len(t) for t in traces))
     return [(_result(traces[b], cfg, t_end[b], skips[b], stall[b]),
              float(lg[b])) for b in range(n)]
 
@@ -1701,7 +1741,8 @@ def sweep_traces(traces: Sequence[CompiledTrace],
     with x64():
         carry = _init_carry(len(cfgs_p), base.burst_bytes)
         _, ys = _run_chunked(sweep_fn, carry, chunks, idxs,
-                             _design_arrays(cfgs_p), bucket, pick=pick)
+                             _design_arrays(cfgs_p), bucket,
+                             sum(len(t) for t in traces) * n, pick=pick)
     t_end, skips, stall, _ = ys
     return [[_result(traces[s], cfgs[j], t_end[j][s], skips[j][s],
                      stall[j][s]) for j in range(n)]

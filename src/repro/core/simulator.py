@@ -29,7 +29,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 
-from . import fastsim
+from . import fastsim, probe
 from .designs import DESIGNS, EngineConfig, get_design
 from .timing import LoadStreamModel, PipelineSimulator, TimingResult
 from .tiling import ALG1_POLICY, GemmSpec, RegPolicy, lowered_stream
@@ -169,8 +169,10 @@ def sweep_workload(specs: list[GemmSpec], designs: list | None = None,
     cfgs = _as_configs(designs)
     if backend == "reference":
         return [sweep_designs(spec, designs, policy) for spec in specs]
-    traces = [gemm_trace(spec, policy) for spec in specs]
+    with probe.span("sim.lower"):
+        traces = [gemm_trace(spec, policy) for spec in specs]
     grid = fastsim.sweep_traces(traces, cfgs, backend=backend)
-    return [{cfg.name: _to_report(spec, cfg, res)
-             for cfg, res in zip(cfgs, row)}
-            for spec, row in zip(specs, grid)]
+    with probe.span("sim.report"):
+        return [{cfg.name: _to_report(spec, cfg, res)
+                 for cfg, res in zip(cfgs, row)}
+                for spec, row in zip(specs, grid)]
