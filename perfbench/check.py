@@ -1,8 +1,10 @@
 """The comparison that decides ``correct``.
 
-Every question the window answered is held against the plain reference
-(``perfbench/reference.py``), computed in float64 on the host once the
-window has closed:
+Every question the window answered is held against the plain reference,
+computed in float64 on the host once the window has closed: the layer
+equations of the configuration's adapter (``layer_gemms`` of
+``perfbench/arch/<model_type>.py``), then the lowering and timing of
+``perfbench/reference.py``:
 
 ``unanswered``
     (GEMM, design) pairs due from the reference's GEMM list for which the
@@ -46,22 +48,24 @@ def _rel(a: float, b: float) -> float:
     return abs(a - b) / abs(b) if b else abs(a - b)
 
 
-def compare(answered: list[dict], cfg: dict, mix: dict, seed: int) -> dict:
-    """``{name: {"value": reading, "limit": limit}}`` over ``answered``.
+def compare(answered: list[dict], c: dict, seed: int) -> dict:
+    """``{name: {"value": reading, "limit": limit}}`` over ``answered``,
+    the questions of cell ``c`` (as ``run.load_cell`` gives it).
 
     Each answered question carries ``gemms`` (``(name, M, K, N)`` as the
     program built them) and ``results`` (per GEMM, ``{design name:
     {cycles, n_mm, n_tl, n_ts, wl_skips, utilization}}``).
     """
     rng = random.Random(f"check:{seed}")
-    k = mix.get("check_designs_per_gemm")
+    layer_gemms, cfg = c["arch"].layer_gemms, c["config"]
+    k = c["mix"].get("check_designs_per_gemm")
     got = dict.fromkeys(LIMITS, 0)
     got["cycles_rel_gap"] = got["util_rel_gap"] = 0.0
     memo: dict = {}
     streams: dict = {}
     for q in answered:
-        want = [g[1:] for g in reference.layer_gemms(
-            cfg, q["batch"], q["seq"], q["phase"])]
+        want = [g[1:] for g in layer_gemms(cfg, q["batch"], q["seq"],
+                                           q["phase"])]
         have = [tuple(g[1:]) for g in q["gemms"]]
         got["shape_mismatch"] += abs(len(want) - len(have)) + sum(
             w != h for w, h in zip(want, have))

@@ -85,7 +85,7 @@ def control_answers(c: dict, seed: int, n_questions: int,
     qs = questions(c["mix"], c["table"], seed)
     for _ in range(n_questions):
         q = next(qs)
-        gemms = reference.layer_gemms(c["config"], q["batch"], q["seq"],
+        gemms = c["arch"].layer_gemms(c["config"], q["batch"], q["seq"],
                                       q["phase"])
         out.append({**q, "gemms": gemms,
                     "results": [_LazyRow(g[1:], q["designs"], memo, streams,
@@ -96,8 +96,8 @@ def control_answers(c: dict, seed: int, n_questions: int,
 def run_control(c: dict, seed: int, n_questions: int,
                 kind: str | None = None) -> dict:
     kind = kind or c["mix"]["control"]
-    checks = check.compare(control_answers(c, seed, n_questions, kind),
-                           c["config"], c["mix"], seed)
+    checks = check.compare(control_answers(c, seed, n_questions, kind), c,
+                           seed)
     return {"seed": seed, "kind": kind, "correct": check.passed(checks),
             "checks": checks}
 

@@ -1,13 +1,12 @@
 """Plain reference for the design-sweep cells.
 
 Independent of the package under test: it imports nothing from ``repro``
-and takes only the benchmark's own configuration, mix and design files.
-Three pieces, each a straight transcription of the paper's model:
+and takes only the benchmark's own design files and GEMM shapes. The
+shapes come from the layer equations of each architecture's adapter
+(``perfbench/arch/<model_type>.py``); what follows depends on no
+architecture. Two pieces, each a straight transcription of the paper's
+model:
 
-``layer_gemms``
-    A layer's projection GEMMs from the configuration's published widths
-    (fused ``qkv`` and ``wo``; gate, up and down per dense FFN or per
-    routed expert under balanced routing).
 ``lower``
     Algorithm 1's register-aware lowering of one GEMM into ``rasa_tl`` /
     ``rasa_mm`` / ``rasa_ts`` over eight tile registers: a 2x2 C block,
@@ -34,35 +33,6 @@ MC, NC, A_REGS, B_REGS = 2, 2, 2, 2
 C_BASE, A_BASE, B_BASE = 0, MC * NC, MC * NC + A_REGS
 
 TL, TS, MM = 0, 1, 2
-
-
-def layer_gemms(cfg: dict, batch: int, seq: int, phase: str
-                ) -> list[tuple[str, int, int, int]]:
-    """``(name, M, K, N)`` of every projection GEMM, layer by layer."""
-    m = batch * seq if phase == "prefill" else batch
-    d = cfg["hidden_size"]
-    hd = cfg["head_dim"]
-    h, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    one: list[tuple[str, int, int, int]] = [
-        ("attn.qkv", m, d, (h + 2 * kv) * hd),
-        ("attn.wo", m, h * hd, d),
-    ]
-    ff = cfg["intermediate_size"]
-    experts = cfg.get("num_local_experts", 0)
-    if experts:
-        routed = m * cfg["num_experts_per_tok"]
-        held = min(experts, routed)
-        m_e = math.ceil(routed / held)
-        for _ in range(held):
-            one += [("moe.gate", m_e, d, ff), ("moe.up", m_e, d, ff),
-                    ("moe.down", m_e, ff, d)]
-    else:
-        one += [("ffn.gate", m, d, ff), ("ffn.up", m, d, ff),
-                ("ffn.down", m, ff, d)]
-    if cfg["attention_scores"]:
-        raise NotImplementedError("attention-score GEMMs have no reference")
-    head = [("head", m, d, cfg["vocab_size"])] if cfg["lm_head"] else []
-    return one * cfg["num_hidden_layers"] + head
 
 
 def lower(M: int, K: int, N: int) -> list[tuple[int, int, int, int]]:

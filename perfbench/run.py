@@ -4,11 +4,15 @@
     python3 perfbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell names a configuration (``perfbench/configs/<config>.json``, the
-sizes as run) and a traffic mix (``perfbench/traffic/<traffic>.json``,
-parameters of the one question generator in ``questions.py``). A run:
+sizes as run), whose ``model_type`` names its architecture's adapter
+(``perfbench/arch/<model_type>.py``: the program's model and the plain
+reference of its layer equations), and a traffic mix
+(``perfbench/traffic/<traffic>.json``, parameters of the one question
+generator in ``questions.py``). A run:
 
-1. refuses, before any question, a platform that is not a TPU or fewer
-   chips than the cell asks for (exit 3, no result);
+1. refuses, before any question, a configuration with no adapter, a
+   platform that is not a TPU or fewer chips than the cell asks for
+   (exit 3, no result);
 2. set-up: imports, the chip, programs from the persistent compilation
    cache in ``<checkout>/.jax_cache``, and one warm-up question drawn
    outside the window's list (``setup_s`` ends here);
@@ -36,6 +40,7 @@ import argparse  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
+import resource  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -58,14 +63,35 @@ def _read_json(path: Path):
         return json.load(f)
 
 
-def load_cell(name: str, root: Path = ROOT) -> dict:
-    """The cell's entry, configuration, mix, design table and metrics."""
+def _module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_cell(name: str, root: Path | None = None) -> dict:
+    """The cell's entry, configuration, architecture adapter, mix, design
+    table and metrics.
+
+    The adapter is ``perfbench/arch/<model_type>.py`` under ``root``; a
+    configuration whose ``model_type`` has none raises
+    ``FileNotFoundError`` naming the file looked for.
+    """
+    root = ROOT if root is None else root
     bench = _read_json(root / "BENCHMARK.json")
     cell = next(w for w in bench["workloads"] if w["name"] == name)
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    cfg = _read_json(root / conf["file"])
+    arch = root / "perfbench" / "arch" / f"{cfg['model_type']}.py"
+    if not arch.is_file():
+        raise FileNotFoundError(
+            f"{conf['file']} has model_type {cfg['model_type']!r}, and "
+            f"there is no adapter {arch}")
     return {
         "cell": cell,
-        "config": _read_json(root / conf["file"]),
+        "config": cfg,
+        "arch": _module(arch, f"perfbench_arch_{cfg['model_type']}"),
         "mix": _read_json(root / "perfbench" / "traffic"
                           / f"{cell['traffic']}.json"),
         "table": _read_json(root / "perfbench" / "designs.json"),
@@ -74,35 +100,19 @@ def load_cell(name: str, root: Path = ROOT) -> dict:
     }
 
 
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.config import ModelConfig, MoEConfig
-
-    experts = cfg.get("num_local_experts", 0)
-    moe = (MoEConfig(n_experts=experts, top_k=cfg["num_experts_per_tok"],
-                     d_ff_expert=cfg["intermediate_size"])
-           if experts else None)
-    return ModelConfig(
-        name=cfg["name"], family="moe" if experts else "dense",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        vocab=cfg["vocab_size"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=0 if experts else cfg["intermediate_size"],
-        head_dim=cfg["head_dim"],
-        act={"silu": "swiglu"}[cfg["hidden_act"]], moe=moe)
-
-
 def _log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
+def _peak_rss() -> str:
+    """This process's peak resident memory so far (Linux counts KiB)."""
+    kib = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
+    return f"host peak RSS {kib / 2**20:.2f} GiB"
+
+
 def _metric_reader(name: str):
-    path = BENCH_DIR / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"perfbench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(BENCH_DIR / "metrics" / f"{name}.py",
+                   f"perfbench_metric_{name}").read
 
 
 def run_cell(c: dict, seed: int, seconds: float, trace: bool,
@@ -110,14 +120,12 @@ def run_cell(c: dict, seed: int, seconds: float, trace: bool,
     """Set-up, window and check of one run; returns the result dict."""
     import jax
     import repro.core as core
-    from repro.workload.compile import CompileOptions, compile_workload
+    from repro.workload.compile import compile_workload
 
     from perfbench.clock import CompileClock
 
-    cfg, mix, table = c["config"], c["mix"], c["table"]
-    model = model_config(cfg)
-    opts = CompileOptions(include_head=cfg["lm_head"],
-                          attention_scores=cfg["attention_scores"])
+    mix, table = c["mix"], c["table"]
+    model, opts = c["arch"].program(c["config"])
     fields = set(core.EngineConfig.__dataclass_fields__)
 
     def answer(q: dict, max_gemms: int | None = None):
@@ -141,14 +149,18 @@ def run_cell(c: dict, seed: int, seconds: float, trace: bool,
         answer(warm, wopts.get("max_gemms"))
     _log(f"warm-up: {time.perf_counter() - tw:.3f} s, compile "
          f"{setup_clock.seconds:.3f} s, {setup_clock.compiles} compiles, "
-         f"{setup_clock.cache_hits} cache hits")
+         f"{setup_clock.cache_hits} cache hits, {_peak_rss()}")
 
     answered: list[dict] = []
     instrs = 0
     qs = questions(mix, table, seed)
     if trace:
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
-        jax.profiler.start_trace(str(TRACE_DIR))
+        # the Python tracer would record every Python call of the
+        # lowering: traced prefill questions took 65% longer with it
+        popts = jax.profiler.ProfileOptions()
+        popts.python_tracer_level = 0
+        jax.profiler.start_trace(str(TRACE_DIR), profiler_options=popts)
     with CompileClock() as clock:
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench.window"):
@@ -175,6 +187,7 @@ def run_cell(c: dict, seed: int, seconds: float, trace: bool,
         t1 = time.perf_counter()
     if trace:
         jax.profiler.stop_trace()
+    _log(f"window: {t1 - t0:.3f} s, {_peak_rss()}")
 
     stats = [d.memory_stats() or {} for d in devices]
     device = {"platform": devices[0].platform,
@@ -185,8 +198,8 @@ def run_cell(c: dict, seed: int, seconds: float, trace: bool,
     gc.collect()
 
     tr = time.perf_counter()
-    checks = check.compare(answered, cfg, mix, seed)
-    _log(f"reference: {time.perf_counter() - tr:.3f} s")
+    checks = check.compare(answered, c, seed)
+    _log(f"reference: {time.perf_counter() - tr:.3f} s, {_peak_rss()}")
     failed = sum(any(len(row) < len(q["designs"]) for row in q["results"])
                  or len(q["results"]) < len(q["gemms"]) for q in answered)
     out = {"correct": check.passed(checks), "attempted": len(answered),
@@ -199,6 +212,7 @@ def run_cell(c: dict, seed: int, seconds: float, trace: bool,
                    for m in c["end_to_end"]}
     else:
         from perfbench import xtrace
+        tx = time.perf_counter()
         reduced = xtrace.reduce_events(xtrace.load(str(TRACE_DIR)))
         run = {"questions": len(answered), "trace": reduced,
                "compiles_in_window": clock.compiles}
@@ -209,6 +223,7 @@ def run_cell(c: dict, seed: int, seconds: float, trace: bool,
                 _log(f"metric {m['name']}: nothing to read in the trace")
             else:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+        _log(f"trace read: {time.perf_counter() - tx:.3f} s, {_peak_rss()}")
         if reduced is not None:
             device.update(busy_s=reduced["busy_s"],
                           window_s=reduced["window_s"])
@@ -238,7 +253,11 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--seconds", type=float, required=True)
     p.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = p.parse_args(argv)
-    c = load_cell(args.workload)
+    try:
+        c = load_cell(args.workload)
+    except FileNotFoundError as e:
+        _log(f"refused: {e}")
+        return 3
 
     import jax
     devices = jax.devices()

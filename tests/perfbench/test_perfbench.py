@@ -1,7 +1,8 @@
 """The chip benchmark's own tests, on the CPU at small sizes.
 
 They cover the question generator, the plain reference against the
-package's oracle, the trace reduction on a recorded trace, the refusal
+package's oracle and the architecture adapters against the program's
+layer compiler, the trace reduction on a recorded trace, the refusal
 of a non-TPU platform, the result line's keys, the float32 control and
 the faults that must turn ``correct`` false.
 """
@@ -120,13 +121,14 @@ def test_reference_matches_the_package_oracle(shape):
                                              (3, 128, "decode"),
                                              (8, 128, "decode")])
 def test_reference_layer_matches_compile_workload(cell, batch, seq, phase):
-    from repro.workload.compile import CompileOptions, compile_workload
+    from repro.workload.compile import compile_workload
 
     c = run.load_cell(cell)
-    wl = compile_workload(run.model_config(c["config"]), batch=batch,
-                          seq=seq, phase=phase, options=CompileOptions())
+    model, opts = c["arch"].program(c["config"])
+    wl = compile_workload(model, batch=batch, seq=seq, phase=phase,
+                          options=opts)
     assert [(s.M, s.K, s.N) for s in wl.specs] == [
-        g[1:] for g in reference.layer_gemms(c["config"], batch, seq, phase)]
+        g[1:] for g in c["arch"].layer_gemms(c["config"], batch, seq, phase)]
 
 
 # ------------------------------------------------------------ trace reduction
@@ -311,12 +313,10 @@ def test_a_fault_in_one_design_is_caught_by_the_sampled_check(bad):
         s = reference.lower(*g[1:])
         rows.append({d["name"]: dict(reference.simulate(s, d))
                      for d in q["designs"]})
-    assert check.passed(check.compare([{**q, "results": rows}], c["config"],
-                                      c["mix"], 5))
+    assert check.passed(check.compare([{**q, "results": rows}], c, 5))
     for row in rows:
         row[TABLE[bad]["name"]]["cycles"] += 1
-    assert not check.passed(check.compare([{**q, "results": rows}],
-                                          c["config"], c["mix"], 5))
+    assert not check.passed(check.compare([{**q, "results": rows}], c, 5))
 
 
 def test_a_sound_tiny_run_is_correct():
