@@ -22,6 +22,21 @@ class MoEConfig:
     #: independent dispatch groups (per-shard EP-style dispatch; keeps the
     #: sort/scatter batched over a DP-sharded dim -- see models/moe.py)
     dispatch_groups: int = 16
+    #: shared experts every token passes through beside the routed ones,
+    #: fused into one SwiGLU of width ``n_shared * d_ff_expert``
+    n_shared: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1):
+    keys and values are up-projected from one cached latent of width
+    ``kv_lora_rank``, beside a rotary key of ``qk_rope_head_dim`` shared
+    by every head."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,6 +101,11 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     hybrid: Optional[HybridConfig] = None
+    #: latent attention in place of the GQA projections
+    mla: Optional[MLAConfig] = None
+    #: leading layers that run the dense FFN of ``d_ff`` before the MoE
+    #: layers begin (DeepSeek's ``first_k_dense_replace``)
+    first_dense: int = 0
     #: stub modality frontend: none | vision | audio (input_specs provides
     #: precomputed patch/frame embeddings -- see DESIGN.md §4)
     frontend: str = "none"

@@ -45,7 +45,7 @@ from . import probe
 from .designs import EngineConfig
 from .isa import NUM_TREGS
 from .timing import LoadStreamModel, TimingResult
-from .trace import OP_END, OP_MM, OP_TL, OP_TS, CompiledTrace
+from .trace import OP_END, OP_MM, OP_TL, OP_TS, CompiledTrace, shape_key
 
 #: below this many total instructions (batch x length) the scan's compile +
 #: dispatch overhead beats the win, and ``backend="fast"`` stays on numpy.
@@ -1464,8 +1464,18 @@ def _port_static(ana: _MMAnalysis, sig) -> tuple[np.ndarray, float]:
 
 
 def _sweep_port_mm(trace: CompiledTrace, cfgs: Sequence[EngineConfig],
-                   params: StreamModelParams | None) -> list[TimingResult]:
-    """The MM-only jax sweep (see section comment above)."""
+                   params: StreamModelParams | None,
+                   scanned: set | None = None) -> list[TimingResult]:
+    """The MM-only jax sweep (see section comment above).
+
+    ``scanned`` holds the ``(shape key, load signature)`` pairs an
+    enclosing sweep has already scanned (:func:`repro.core.trace.shape_key`).
+    Chunk calls that scan an equal stream under an equal signature again
+    are counted as ``sim.repeat_chunk_calls``, the others as
+    ``sim.fresh_chunk_calls``; each dispatch span carries the one that
+    applies.
+    """
+    scanned = set() if scanned is None else scanned
     with probe.span("sim.analyse"):
         ana = _mm_analysis(trace)
         groups: dict[tuple, list[int]] = {}
@@ -1484,6 +1494,9 @@ def _sweep_port_mm(trace: CompiledTrace, cfgs: Sequence[EngineConfig],
             continue
         n_chunks = -(-n_mm // CHUNK)
         B = _pow2(len(members), lo=1)
+        key = (shape_key(trace), sig)
+        repeat = key in scanned
+        scanned.add(key)
         with x64():
             with probe.span("sim.stage"):
                 cols = _mm_columns(ana, done_tl, sig[0], n_chunks * CHUNK)
@@ -1500,7 +1513,9 @@ def _sweep_port_mm(trace: CompiledTrace, cfgs: Sequence[EngineConfig],
                             scan_steps=n_chunks * CHUNK * B,
                             useful_steps=n_mm * len(members),
                             h2d_bytes=(sum(c.nbytes for c in carry)
-                                       + n_chunks * per_call)):
+                                       + n_chunks * per_call),
+                            **{"repeat_chunk_calls" if repeat
+                               else "fresh_chunk_calls": n_chunks}):
                 for k in range(n_chunks):
                     sl = slice(k * CHUNK, (k + 1) * CHUNK)
                     carry = fn(carry, tuple(col[sl] for col in cols), design)
@@ -1723,7 +1738,8 @@ def sweep_traces(traces: Sequence[CompiledTrace],
 
     base = params or StreamModelParams(load_ports=1)
     if base.is_port_model and base.store_ports is None:
-        return [_sweep_port_mm(t, cfgs, params) for t in traces]
+        scanned: set = set()
+        return [_sweep_port_mm(t, cfgs, params, scanned) for t in traces]
     sweep_fn = _jax_fns(base.is_port_model, emit_ends=True)[0]
     packed, idx, ends = _pack_lane(traces)
     chunks, idxs = _chunk_single(packed, idx)

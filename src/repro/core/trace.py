@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import weakref
 from typing import Iterable, Sequence
 
 import numpy as np
@@ -217,6 +218,17 @@ def _compiled_trace_cached(specs: tuple[GemmSpec, ...],
     return compile_stream(_chain(specs, policy))
 
 
+#: the name-free specs and policy of every trace ``compiled_trace`` made
+_SHAPE_KEYS: weakref.WeakKeyDictionary = weakref.WeakKeyDictionary()
+
+
+def shape_key(trace: CompiledTrace):
+    """What ``trace``'s stream is a function of, where ``compiled_trace``
+    made it: the specs without their names, and the policy. Any other
+    trace is its own key. Equal keys mean equal streams."""
+    return _SHAPE_KEYS.get(trace, trace)
+
+
 def compiled_trace(specs: tuple[GemmSpec, ...],
                    policy: RegPolicy) -> CompiledTrace:
     """The cached ``CompiledTrace`` of ``specs`` lowered back to back.
@@ -226,8 +238,12 @@ def compiled_trace(specs: tuple[GemmSpec, ...],
     """
     mm = sum(m * k * n for m, k, n in (s.tiles() for s in specs))
     if mm > _TRACE_CACHE_MAX_MM:
-        return compile_stream(_chain(specs, policy))
-    return _compiled_trace_cached(specs, policy)
+        trace = compile_stream(_chain(specs, policy))
+    else:
+        trace = _compiled_trace_cached(specs, policy)
+    _SHAPE_KEYS[trace] = (
+        tuple(dataclasses.replace(s, name="") for s in specs), policy)
+    return trace
 
 
 def gemm_trace(spec: GemmSpec, policy: RegPolicy) -> CompiledTrace:

@@ -25,15 +25,30 @@ Lowering per block
   with ``CompileOptions.attention_scores`` also the ``QK^T`` / ``PV`` score
   GEMMs, folded along M over the ``batch * n_heads`` instances (a
   block-diagonal approximation: MAC-exact, reuse-approximate).
+* Latent attention (``ModelConfig.mla``, DeepSeek-V2 §2.1), with ``h``
+  heads, latent ``r``, rotary ``p``, no-rope ``n`` and value ``v`` widths.
+  Prefill takes the explicit path: ``q`` [d, h*(n+p)], ``kv_a``
+  [d, r+p], ``kv_b`` [r, h*(n+v)], ``o`` [h*v, d]; with
+  ``attention_scores`` also one ``scores`` [seq, n+p, seq] / ``context``
+  [seq, seq, v] pair per (sequence, head), since every head has keys of
+  its own.  Decode takes the absorbed path, W^UK folded into the query
+  and W^UV into the output projection: ``q_absorbed`` [d, h*(r+p)],
+  ``kv_a`` [d, r+p], ``o_absorbed`` [h*r, d]; with ``attention_scores``
+  one ``scores`` [h, r+p, ctx] / ``context`` [h, ctx, r] pair per
+  sequence -- every head of a sequence reads the same latent cache, so
+  stacking the heads along M is exact.
 * Dense FFN: ``swiglu``/``geglu`` emit gate + up + down (one fused
   [d, 2*d_ff] gate-up GEMM under ``fuse_gate_up``); other activations
-  emit up + down.
+  emit up + down.  MoE models run it in their ``first_dense`` leading
+  layers.
 * MoE: balanced ("uniform") routing over ``n_active = min(n_experts,
   routed_tokens, max_experts)`` experts, ``ceil(routed_tokens /
   n_active)`` tokens each, where ``routed_tokens = M * top_k``.  Each
   modeled expert's GEMM pair is one *placement group* (``L{i}.e{j}``):
   schedulers place a group atomically on one core, so distinct experts
-  spreading over cores is exactly expert parallelism.
+  spreading over cores is exactly expert parallelism.  ``n_shared``
+  shared experts run every token through one SwiGLU of width
+  ``n_shared * d_ff_expert``, placement group ``L{i}.shared``.
 * SSM (Mamba2): ``in_proj`` / ``out_proj`` projections plus the SSD core
   costed via the :mod:`repro.kernels.ssd_chunk` decomposition -- per
   (batch, head, chunk) the chunked scan is four matmuls (``cb = C @ B^T``,
@@ -59,6 +74,7 @@ import math
 from typing import Iterable
 
 from ..config import ModelConfig
+from ..core import probe
 from ..core.tiling import GemmSpec
 
 PHASES = ("prefill", "decode")
@@ -105,7 +121,7 @@ class WorkloadOp:
 
     spec: GemmSpec
     layer: int                      # layer index (-1 for the LM head)
-    block: str                      # attn | ffn | moe | ssm | head
+    block: str                      # attn | mla | ffn | moe | ssm | head
     group: str | None = None
 
 
@@ -205,20 +221,56 @@ def _moe_ops(m: ModelConfig, arch: str, layer: int, m_tokens: int,
              o: CompileOptions) -> Iterable[WorkloadOp]:
     moe = m.moe
     assert moe is not None
-    d, ffe = o.cap(m.d_model), o.cap(moe.d_ff_expert)
+    d = o.cap(m.d_model)
     routed = m_tokens * moe.top_k
     n_active = min(moe.n_experts, routed)
     if o.max_experts:
         n_active = min(n_active, o.max_experts)
     m_e = math.ceil(routed / n_active)
-    for e in range(n_active):
-        group = f"L{layer}.e{e}"
-        mk = lambda op, M, K, N: WorkloadOp(
-            GemmSpec(f"{arch}.moe.{op}", M, K, N), layer, "moe", group)
+
+    def expert(group: str, kind: str, M: int, ff: int):
+        mk = lambda op, K, N: WorkloadOp(
+            GemmSpec(f"{arch}.moe.{kind}{op}", M, K, N), layer, "moe", group)
         if m.act in ("swiglu", "geglu") and not m.fuse_gate_up:
-            yield mk("gate", m_e, d, ffe)
-        yield mk("up", m_e, d, ffe)
-        yield mk("down", m_e, ffe, d)
+            yield mk("gate", d, ff)
+        yield mk("up", d, ff)
+        yield mk("down", ff, d)
+
+    for e in range(n_active):
+        yield from expert(f"L{layer}.e{e}", "", m_e, o.cap(moe.d_ff_expert))
+    if moe.n_shared:
+        yield from expert(f"L{layer}.shared", "shared.", m_tokens,
+                          o.cap(moe.n_shared * moe.d_ff_expert))
+
+
+def _mla_ops(m: ModelConfig, arch: str, layer: int, m_tokens: int,
+             batch: int, seq: int, phase: str, o: CompileOptions
+             ) -> Iterable[WorkloadOp]:
+    a = m.mla
+    assert a is not None
+    d, h = m.d_model, m.n_heads
+    r, rope = a.kv_lora_rank, a.qk_rope_head_dim
+    nope, v = a.qk_nope_head_dim, a.v_head_dim
+    mk = lambda op, M, K, N: WorkloadOp(
+        GemmSpec(f"{arch}.mla.{op}", o.cap(M), o.cap(K), o.cap(N)), layer,
+        "mla")
+    if phase == "prefill":
+        yield mk("q", m_tokens, d, h * (nope + rope))
+        yield mk("kv_a", m_tokens, d, r + rope)
+        yield mk("kv_b", m_tokens, r, h * (nope + v))
+        if o.attention_scores:
+            for _ in range(batch * h):
+                yield mk("scores", seq, nope + rope, seq)
+                yield mk("context", seq, seq, v)
+        yield mk("o", m_tokens, h * v, d)
+    else:
+        yield mk("q_absorbed", m_tokens, d, h * (r + rope))
+        yield mk("kv_a", m_tokens, d, r + rope)
+        if o.attention_scores:
+            for _ in range(batch):
+                yield mk("scores", h, r + rope, seq)
+                yield mk("context", h, seq, r)
+        yield mk("o_absorbed", m_tokens, h * r, d)
 
 
 def _ssm_ops(m: ModelConfig, arch: str, layer: int, m_tokens: int,
@@ -260,7 +312,8 @@ def layer_ops(model, layer: int, *, batch: int, seq: int,
 
     Hybrid models (Zamba2-style) interleave: every layer runs the SSM
     block, and layers at the shared-attention stride additionally run the
-    attention + FFN block.
+    attention + FFN block.  MoE models run the dense FFN in their
+    ``first_dense`` leading layers and the experts from then on.
     """
     m, arch = _resolve_model(model)
     if phase not in PHASES:
@@ -274,9 +327,10 @@ def layer_ops(model, layer: int, *, batch: int, seq: int,
     if m.ssm is not None:
         out += _ssm_ops(m, arch, layer, m_tokens, batch, seq, phase, options)
     if attn_layer:
-        out += _attention_ops(m, arch, layer, m_tokens, batch, seq, phase,
-                              options)
-        if m.moe is not None:
+        attention = _mla_ops if m.mla is not None else _attention_ops
+        out += attention(m, arch, layer, m_tokens, batch, seq, phase,
+                         options)
+        if m.moe is not None and layer >= m.first_dense:
             out += _moe_ops(m, arch, layer, m_tokens, options)
         elif m.d_ff:
             out += _ffn_ops(m, arch, layer, m_tokens, options)
@@ -299,16 +353,24 @@ def compile_workload(model, *, batch: int, seq: int,
     n = m.n_layers
     modeled = min(n, options.max_layers) if options.max_layers else n
     ops: list[WorkloadOp] = []
-    for layer in range(modeled):
-        ops += layer_ops(m, layer, batch=batch, seq=seq, phase=phase,
-                         options=options)
-    if options.include_head:
-        m_tokens = options.cap(batch * seq if phase == "prefill" else batch)
-        for cb in range(m.n_codebooks):
-            ops.append(WorkloadOp(
-                GemmSpec(f"{arch}.head", m_tokens,
-                         options.cap(m.d_model), options.cap(m.vocab)),
-                -1, "head"))
+    with probe.span("workload.compile") as sp:
+        for layer in range(modeled):
+            ops += layer_ops(m, layer, batch=batch, seq=seq, phase=phase,
+                             options=options)
+        if options.include_head:
+            m_tokens = options.cap(batch * seq if phase == "prefill"
+                                   else batch)
+            for cb in range(m.n_codebooks):
+                ops.append(WorkloadOp(
+                    GemmSpec(f"{arch}.head", m_tokens,
+                             options.cap(m.d_model), options.cap(m.vocab)),
+                    -1, "head"))
+        probe.count(
+            sp, gemms=len(ops),
+            mla_gemms=sum(op.block == "mla" for op in ops),
+            expert_gemms=sum(op.block == "moe" for op in ops),
+            dense_lead_gemms=sum(op.block == "ffn" and op.layer < m.first_dense
+                                 for op in ops))
     if not ops:
         raise ValueError(f"{arch}: no GEMMs lowered -- "
                          f"check the model's block configuration")
